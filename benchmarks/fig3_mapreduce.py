@@ -3,6 +3,10 @@
 the paper's 1..16-machine Spark sweep), vs SeqCoreset and StreamCoreset at
 the same tau.
 
+The children emulate l machines on the CPU backend (``JAX_PLATFORMS=cpu``,
+rows labelled ``platform=cpu``): an accelerator belongs to one process, and
+the parent that runs the other suites may already hold it.
+
 Container scale: n=20000, tau=64, k=8.
 """
 from __future__ import annotations
@@ -58,12 +62,13 @@ def run(n=20000, k=8, tau=64, quick=False):
             env = dict(os.environ)
             env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={l}"
             env["PYTHONPATH"] = os.path.join(src, "src")
+            env["JAX_PLATFORMS"] = "cpu"
             r = subprocess.run([sys.executable, "-c", code],
                                capture_output=True, text=True, env=env,
                                timeout=1800)
             assert r.returncode == 0, r.stderr[-2000:]
             rec = json.loads(r.stdout.strip().splitlines()[-1])
-            rec.update(dataset=ds, l=l)
+            rec.update(dataset=ds, l=l, platform="cpu")
             rows.append(rec)
     return rows
 
@@ -76,6 +81,7 @@ def main(quick=False):
     return [
         csv_line(
             f"fig3_{r['dataset']}/l={r['l']}", r["time_s"] * 1e6,
+            f"platform={r['platform']};"
             f"diversity_ratio={r['diversity']/best[r['dataset']]:.4f};"
             f"coreset_s={r['coreset_s']:.2f};"
             f"per_shard_s={r['per_shard_s']:.2f};"

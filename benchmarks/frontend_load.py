@@ -73,6 +73,8 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import csv_line, songs_like
 
 LEVELS = (1, 4, 16)
@@ -423,6 +425,7 @@ if __name__ == "__main__":
     ap.add_argument("--json", action="store_true")
     ap.add_argument("--check", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.check:
         sys.exit(check(quick=True))
     for line in main(quick=args.quick, emit_json=args.json):

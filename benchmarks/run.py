@@ -19,6 +19,8 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -30,6 +32,7 @@ def main() -> None:
                          "committed BENCH_serve.json / "
                          "BENCH_frontend.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.check:
         from . import frontend_load, serve_bench

@@ -94,6 +94,8 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 from .common import Timer, csv_line, songs_like, songs_multilabel
 
 BLOCK_SIZE = 128
@@ -1245,6 +1247,7 @@ if __name__ == "__main__":
                     help="compare a fresh --quick run against the committed "
                          "BENCH_serve.json; exit 1 on >20%% regression")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.check:
         sys.exit(check())
     print("name,us_per_call,derived")

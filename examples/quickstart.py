@@ -25,7 +25,7 @@ def main():
     for setting in ("sequential", "streaming", "mapreduce"):
         kw = dict(setting=setting, tau=64)
         if setting == "mapreduce":
-            # launch.mesh.make_mesh papers over the AxisType API drift
+            # every mesh in the repo is built by launch.mesh.make_mesh
             from repro.launch.mesh import make_mesh
 
             kw["mesh"] = make_mesh((len(jax.devices()),), ("data",))

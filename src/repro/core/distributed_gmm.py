@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..kernels import ops
-from ..compat import axis_size, shard_map
 from .coreset import Coreset, compress, default_capacity, extraction_mask
 from .matroid import MatroidSpec
 
@@ -42,7 +41,7 @@ def _global_gmm_shard(pts, valid, tau: int, axes: Sequence[str]):
 
     shard_idx = jnp.int32(0)
     for name in axes:
-        shard_idx = shard_idx * axis_size(name) + jax.lax.axis_index(
+        shard_idx = shard_idx * jax.lax.axis_size(name) + jax.lax.axis_index(
             name
         )
 
@@ -120,7 +119,7 @@ def distributed_coreset(
     cap = default_capacity(spec, k, tau)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(data_axes, None), P(data_axes, None), P(data_axes), P()),
         out_specs=(Coreset(P(), P(), P(), P()), P(), P()),
@@ -137,7 +136,7 @@ def distributed_coreset(
         )
         idx = jnp.int32(0)
         for name in data_axes:
-            idx = idx * axis_size(name) + jax.lax.axis_index(name)
+            idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
         cs = compress(pts, cts, mask, cap, base_index=idx * n_local)
         gathered = Coreset(
             *(jax.lax.all_gather(leaf, data_axes, tiled=True) for leaf in cs)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import axis_size, shard_map
 from .coreset import Coreset, compress, default_capacity, extraction_mask, seq_coreset
 from .matroid import MatroidSpec
 
@@ -35,7 +34,7 @@ def _flat_axis_index(axis_names: Sequence[str]) -> jnp.ndarray:
     """Linear shard index over (possibly multiple) mesh axes, C-order."""
     idx = jnp.int32(0)
     for name in axis_names:
-        idx = idx * axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx
 
 
@@ -103,7 +102,7 @@ def mapreduce_coreset(
     pspec = P(data_axes, None)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(pspec, pspec, in_spec, P()),
         out_specs=(

@@ -45,6 +45,13 @@ from .base import (
 from .matching import augment, cats_onehot, feasible_all, swap_feasible
 
 
+def _dot(a, b):
+    """Full-f32 matmul for the gain and swap-value decisions: the host
+    solver computes them in f32/f64, and a default-precision TPU matmul
+    takes bf16 passes that reorder near-tied candidates."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def bucket_pow2(n: int) -> int:
     """Next power of two >= n (>= 1). Shape-bucketing for the jit cache:
     a batch of 5 queries with max k 6 compiles the (8, 8) kernel, and any
@@ -128,7 +135,7 @@ def _greedy_seed(D, cats, caps, allow, k, kmax):
         sel, selmask, counts, nsel = carry
         can = allow & ~selmask & (counts[cats] < caps[cats])
         gains = jnp.where(
-            nsel == 0, rowsum_all, D @ selmask.astype(jnp.float32)
+            nsel == 0, rowsum_all, _dot(D, selmask.astype(jnp.float32))
         )
         v = jnp.argmax(jnp.where(can, gains, -jnp.inf))
         take = (i < k) & jnp.any(can)
@@ -158,7 +165,7 @@ def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
     m = D.shape[0]
     sel, selmask, counts, nsel = _greedy_seed(D, cats, caps, allow, k, kmax)
     selm_f = selmask.astype(jnp.float32)
-    div0 = 0.5 * jnp.dot(selm_f, D @ selm_f)
+    div0 = 0.5 * _dot(selm_f, _dot(D, selm_f))
     slots = jnp.arange(kmax, dtype=jnp.int32)
 
     def v_body(v, st):
@@ -185,7 +192,7 @@ def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
             sel2 = sel[src].at[nsel - 1].set(v)
             selmask2 = selmask.at[uold].set(False).at[v].set(True)
             counts2 = counts.at[cats[uold]].add(-1).at[cat_v].add(1)
-            rowX2 = D @ selmask2.astype(jnp.float32)
+            rowX2 = _dot(D, selmask2.astype(jnp.float32))
             return sel2, selmask2, counts2, rowX2, new_div[ui], True
 
         return jax.lax.cond(any_imp, do_swap, lambda s: s, st)
@@ -200,7 +207,7 @@ def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
         st = jax.lax.fori_loop(0, m, v_body, st)
         return st, sweeps + 1
 
-    rowX0 = D @ selm_f
+    rowX0 = _dot(D, selm_f)
     ls0 = ((sel, selmask, counts, rowX0, div0, nsel == k), jnp.int32(0))
     (sel, selmask, counts, _rowX, div, _imp), _ = jax.lax.while_loop(
         sweep_cond, sweep_body, ls0
@@ -245,7 +252,7 @@ def _greedy_seed_tv(D, oh, allow, k, kmax):
         sel, selmask, ms_pt, nsel = carry
         can = allow & ~selmask & feasible_all(oh, ms_pt, kmax)
         gains = jnp.where(
-            nsel == 0, rowsum_all, D @ selmask.astype(jnp.float32)
+            nsel == 0, rowsum_all, _dot(D, selmask.astype(jnp.float32))
         )
         v = jnp.argmax(jnp.where(can, gains, -jnp.inf))
         take = (i < k) & jnp.any(can)
@@ -278,7 +285,7 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
     m = D.shape[0]
     sel, selmask, ms_pt, nsel = _greedy_seed_tv(D, oh, allow, k, kmax)
     selm_f = selmask.astype(jnp.float32)
-    div0 = 0.5 * jnp.dot(selm_f, D @ selm_f)
+    div0 = 0.5 * _dot(selm_f, _dot(D, selm_f))
     slots = jnp.arange(kmax, dtype=jnp.int32)
 
     def v_body(v, st):
@@ -304,7 +311,7 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
             # rebuild the matching: free u's category, re-insert v
             ms2 = jnp.where(ms_pt == uold, jnp.int32(-1), ms_pt)
             ms2 = augment(oh, ms2, v, kmax)
-            rowX2 = D @ selmask2.astype(jnp.float32)
+            rowX2 = _dot(D, selmask2.astype(jnp.float32))
             return sel2, selmask2, ms2, rowX2, new_div[ui], True
 
         return jax.lax.cond(any_imp, do_swap, lambda s: s, st)
@@ -319,7 +326,7 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
         st = jax.lax.fori_loop(0, m, v_body, st)
         return st, sweeps + 1
 
-    rowX0 = D @ selm_f
+    rowX0 = _dot(D, selm_f)
     ls0 = ((sel, selmask, ms_pt, rowX0, div0, nsel == k), jnp.int32(0))
     (sel, _selmask, _ms, _rowX, div, _imp), _ = jax.lax.while_loop(
         sweep_cond, sweep_body, ls0

@@ -54,32 +54,47 @@ def greedy_matching_slots(
     """First-free-category greedy matching over slot order.
 
     Returns (used: bool[h] categories consumed, matched: bool[SLOT] slots
-    that found a category). Exactly the loop the streaming shrink step has
-    always run — kept bit-identical (tests/test_blocked_ingest.py pins the
-    scan output across refactors).
+    that found a category). Exactly what the sequential loop the streaming
+    shrink step has always run gives — each valid slot, in slot order,
+    takes the first of its categories no earlier slot took (the loop is
+    kept in tests/test_matroid.py as the reference;
+    tests/test_blocked_ingest.py pins the scan output across refactors).
+
+    Computed as the fixed point of one vectorized round: every valid slot
+    picks its first category that no earlier slot holds in the previous
+    round's picks. A slot's greedy pick depends only on earlier slots', so
+    after round r the first r valid slots are final, and the first round
+    that changes nothing has reached the greedy result. The trip count is
+    the longest chain of displaced picks — a handful — instead of the
+    number of valid slots (about k per transversal center), which on a TPU,
+    where each sequential trip pays a fixed cost, dominates the scan step.
     """
     slot_n, _gamma = cats.shape
+    pos = jnp.arange(slot_n, dtype=jnp.int32)
+    cat_ids = jnp.arange(num_categories, dtype=jnp.int32)
+    real = (cats >= 0) & valid[:, None]
+    cc = jnp.maximum(cats, 0)
 
-    def body(s, carry):
-        used, matched = carry
+    def round_(pick):
+        # first[c]: earliest slot holding category c (slot_n when none)
+        first = jnp.min(
+            jnp.where(pick[None, :] == cat_ids[:, None], pos[None, :], slot_n),
+            axis=1,
+        )
+        free = real & (first[cc] >= pos[:, None])
+        j = jnp.argmax(free, axis=1)  # first free category slot
+        got = jnp.take_along_axis(cats, j[:, None], axis=1)[:, 0]
+        return jnp.where(jnp.any(free, axis=1), got, -1)
 
-        def try_slot(carry):
-            used, matched = carry
-            free = (cats[s] >= 0) & ~used[jnp.maximum(cats[s], 0)]
-            j = jnp.argmax(free)  # first free category slot
-            ok = jnp.any(free)
-            cat = jnp.maximum(cats[s, j], 0)
-            used = jax.lax.cond(
-                ok, lambda u: u.at[cat].set(True), lambda u: u, used
-            )
-            matched = matched.at[s].set(ok)
-            return used, matched
-
-        return jax.lax.cond(valid[s], try_slot, lambda c: c, carry)
-
-    used0 = jnp.zeros((num_categories,), bool)
-    matched0 = jnp.zeros((slot_n,), bool)
-    return jax.lax.fori_loop(0, slot_n, body, (used0, matched0))
+    none = jnp.full((slot_n,), -1, jnp.int32)
+    pick, _ = jax.lax.while_loop(
+        lambda c: jnp.any(c[0] != c[1]),
+        lambda c: (round_(c[0]), c[0]),
+        (round_(none), none),
+    )
+    matched = pick >= 0
+    used = jnp.any(pick[None, :] == cat_ids[:, None], axis=1)
+    return used, matched
 
 
 # --------------------------------------------------------------------------

@@ -154,12 +154,12 @@ def _handle_masked(
 ) -> tuple[StreamState, jnp.ndarray]:
     """Alg. 2 HANDLE(x, z, D_z) as masked dense updates.
 
-    The add decision is computed unconditionally (cheap gathers/reductions
-    over one center's slot buffer); the *write pass* — and, for
-    transversal, the greedy-matching shrink that follows a successful add —
-    runs under a ``_cond_once`` guard, so a rejected or disabled HANDLE
-    costs no buffer traffic even under vmap. Executed writes are ``where``-
-    masked per field, which keeps lanes that didn't trigger bit-exact.
+    The add decision and its one-cell writes are computed unconditionally
+    (cheap gathers/reductions over one center's slot buffer), ``where``-
+    masked per field so lanes that didn't trigger stay bit-exact; for
+    transversal, the greedy-matching shrink that follows a successful add
+    runs under a ``_cond_once`` guard over that center's slot row only, so
+    a rejected or disabled HANDLE costs no matching even under vmap.
     Returns ``(state, add)`` — ``add`` is the did-anything-change bit the
     blocked scan uses to decide precheck staleness.
     """
@@ -191,41 +191,40 @@ def _handle_masked(
         raise ValueError(f"jit HANDLE not defined for {spec.kind!r}")
 
     add = add & has_room & enable
-    st = st._replace(overflow=st.overflow + forced)
+    # the add is one masked cell per buffer, written unconditionally: a
+    # branch here would be a batched while under vmap, whose carry select
+    # rewrites the whole state on every point
+    st = st._replace(
+        overflow=st.overflow + forced,
+        dp=st.dp.at[z, free_slot].set(
+            jnp.where(add, x, st.dp[z, free_slot])
+        ),
+        dc=st.dc.at[z, free_slot].set(
+            jnp.where(add, xc, st.dc[z, free_slot])
+        ),
+        dv=st.dv.at[z, free_slot].set(st.dv[z, free_slot] | add),
+        ds=st.ds.at[z, free_slot].set(
+            jnp.where(add, xsrc, st.ds[z, free_slot])
+        ),
+    )
+    if spec.kind == "transversal":
+        # masked shrink: a greedy matching covering k slots is a witnessed
+        # independent size-k subset — keep exactly those slots (post-add
+        # buffers, like the historical cond'd _shrink). The matching stays
+        # a real branch, carrying only the center's slot row.
+        from .solvers.matching import greedy_matching_slots
 
-    def apply_add(st: StreamState) -> StreamState:
-        st = st._replace(
-            dp=st.dp.at[z, free_slot].set(
-                jnp.where(add, x, st.dp[z, free_slot])
-            ),
-            dc=st.dc.at[z, free_slot].set(
-                jnp.where(add, xc, st.dc[z, free_slot])
-            ),
-            dv=st.dv.at[z, free_slot].set(st.dv[z, free_slot] | add),
-            ds=st.ds.at[z, free_slot].set(
-                jnp.where(add, xsrc, st.ds[z, free_slot])
-            ),
-        )
-        if spec.kind == "transversal":
-            # masked shrink: a greedy matching covering k slots is a
-            # witnessed independent size-k subset — keep exactly those
-            # slots (post-add buffers, like the historical cond'd _shrink)
-            from .solvers.matching import greedy_matching_slots
+        dcz = st.dc[z]
 
-            slots_v2 = st.dv[z]
+        def shrink(row):
             _used, matched = greedy_matching_slots(
-                st.dc[z], slots_v2, spec.num_categories
+                dcz, row, spec.num_categories
             )
             size = jnp.sum(matched.astype(jnp.int32))
-            do = add & (size >= k)
-            st = st._replace(
-                dv=st.dv.at[z].set(
-                    jnp.where(do, matched & slots_v2, slots_v2)
-                )
-            )
-        return st
+            return jnp.where(size >= k, matched & row, row)
 
-    return _cond_once(add, apply_add, st), add
+        st = st._replace(dv=st.dv.at[z].set(_cond_once(add, shrink, st.dv[z])))
+    return st, add
 
 
 def _merge_delegates(spec, k, caps, st: StreamState, dead_mask):
@@ -1162,7 +1161,6 @@ def _sharded_mapped_fn(nd: int, spec: MatroidSpec, k: int, tau: int,
     caching on nd alone is sound."""
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map as _shard_map
     from ..launch.mesh import make_mesh
 
     mesh = make_mesh((nd,), ("shards",), devices=jax.devices()[:nd])
@@ -1177,11 +1175,12 @@ def _sharded_mapped_fn(nd: int, spec: MatroidSpec, k: int, tau: int,
 
         return jax.vmap(one)(sts, p, c, v, s)
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(psh, psh, psh, psh, psh, P()),
         out_specs=psh,
+        check_vma=False,
     )
     return jax.jit(mapped, donate_argnums=(0,) if donate else ())
 

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -115,7 +114,7 @@ def flash_attention_fwd(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -265,7 +264,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -297,7 +296,7 @@ def flash_attention_bwd(
             pltpu.VMEM((bk, hd), jnp.float32),
             pltpu.VMEM((bk, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _pdist_kernel(x_ref, y_ref, o_ref):
     k = pl.program_id(2)
@@ -37,7 +35,8 @@ def _pdist_kernel(x_ref, y_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)  # (bn, bd)
     y = y_ref[...].astype(jnp.float32)  # (bm, bd)
     dot = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,  # f32 distances, not bf16 passes
     )  # (bn, bm)
     xn = jnp.sum(x * x, axis=1, keepdims=True)  # (bn, 1)
     yn = jnp.sum(y * y, axis=1, keepdims=True).T  # (1, bm)
@@ -78,7 +77,7 @@ def pairwise_sqdist(
         ],
         out_specs=pl.BlockSpec((bn, bm), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], yp.shape[0]), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 # python literal (not a jnp scalar): pallas kernels must not close over
 # traced array constants
 _F32_MAX = float(jnp.finfo(jnp.float32).max)
@@ -47,8 +45,11 @@ def _precheck_kernel(x_ref, c_ref, m_ref, o_ref, acc_ref, *, nk: int):
 
     x = x_ref[...].astype(jnp.float32)  # (bB, bd)
     c = c_ref[...].astype(jnp.float32)  # (T_pad, bd)
+    # full f32 passes: the scan's fallback margin assumes f32 cancellation
+    # error, which a default-precision (bf16-pass) TPU matmul exceeds
     dot = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # (bB, T_pad)
     xn = jnp.sum(x * x, axis=1, keepdims=True)  # (bB, 1)
     cn = jnp.sum(c * c, axis=1, keepdims=True).T  # (1, T_pad)
@@ -129,7 +130,7 @@ def center_precheck_stats(
         out_specs=pl.BlockSpec((bB, 128), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((xp.shape[0], 128), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bB, tpad), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

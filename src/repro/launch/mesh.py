@@ -15,21 +15,12 @@ import jax
 
 
 def make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` across the AxisType API drift: newer JAX wants
-    explicit ``axis_types``; 0.4.x has neither ``jax.sharding.AxisType`` nor
-    the kwarg. All mesh construction in this repo goes through here."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {} if devices is None else dict(devices=devices)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    try:
-        return jax.make_mesh(shape, axes, **kwargs)
-    except TypeError:  # older make_mesh without devices kwarg
-        from jax.sharding import Mesh
-
-        devs = devices if devices is not None else jax.devices()
-        need = int(np.prod(shape))
-        return Mesh(np.asarray(devs[:need]).reshape(shape), axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding propagated by
+    the compiler). All mesh construction in this repo goes through here."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

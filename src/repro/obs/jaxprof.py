@@ -39,13 +39,7 @@ import threading
 from typing import Optional
 
 import jax
-
-try:
-    from jax import named_scope  # re-export: the in-trace annotation
-except ImportError:  # pragma: no cover - ancient jax
-    @contextlib.contextmanager
-    def named_scope(name):  # type: ignore[misc]
-        yield
+from jax import named_scope  # re-export: the in-trace annotation
 
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 UNATTRIBUTED = "unattributed"

@@ -42,11 +42,10 @@ import sys
 import threading
 from typing import Optional
 
-try:  # the guard's "am I inside a jit trace?" probe
-    from jax.core import trace_state_clean as _trace_state_clean
-except ImportError:  # pragma: no cover - ancient/absent jax
-    def _trace_state_clean() -> bool:
-        return True
+# the guard's "am I inside a jit trace?" probe; JAX no longer exports it
+# from ``jax.core``, and a missing probe must fail the import rather than
+# switch the guard off
+from jax._src.core import trace_state_clean as _trace_state_clean
 
 
 class TracerLeakError(RuntimeError):

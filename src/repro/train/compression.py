@@ -20,8 +20,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
-
 
 def quantize(g: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     scale = jnp.max(jnp.abs(g)) / 127.0
@@ -77,7 +75,7 @@ def pod_allreduce_compressed(
         q = jnp.clip(jnp.round(gf / scale), -127, 127).astype(jnp.int8)
         deq_local = q.astype(jnp.float32) * scale
         new_r = gf - deq_local
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         total = jax.lax.psum(q.astype(jnp.int32), axis_name)
         return total.astype(jnp.float32) * scale / n, new_r
 

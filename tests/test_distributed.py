@@ -67,10 +67,10 @@ def test_compressed_pod_allreduce():
         g_global = jnp.asarray(
             np.random.default_rng(0).normal(size=(8, 64)), jnp.float32)
 
-        from repro.compat import shard_map
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P("pod"), P("pod")),
-                           out_specs=(P("pod"), P("pod")))
+                           out_specs=(P("pod"), P("pod")),
+                           check_vma=False)
         def run(g, r):
             red, new_r = pod_allreduce_compressed(
                 {"g": g[0]}, {"g": r[0]}, "pod")

@@ -189,3 +189,36 @@ def test_uniform_matroid():
     assert m.is_independent([0, 1, 2])
     assert not m.is_independent([0, 1, 2, 3])
     assert not m.is_independent([0, 0, 1])
+
+
+def _greedy_matching_loop(cats, valid, h):
+    """The sequential first-free-category loop over slot order (the
+    reference ``greedy_matching_slots`` must reproduce)."""
+    used = np.zeros(h, bool)
+    matched = np.zeros(len(valid), bool)
+    for s in np.flatnonzero(valid):
+        for c in cats[s]:
+            if c >= 0 and not used[c]:
+                used[c] = matched[s] = True
+                break
+    return used, matched
+
+
+@pytest.mark.parametrize("seed", range(6))
+@pytest.mark.parametrize("slots,h", [(64, 5), (512, 100)])
+def test_greedy_matching_slots_equals_sequential_loop(seed, slots, h):
+    from repro.core.solvers.matching import greedy_matching_slots
+
+    rng = np.random.default_rng(seed)
+    cats = np.full((slots, 3), -1, np.int32)
+    # few topics per region (long displacement chains) on even seeds
+    cats[:, 0] = rng.integers(0, h if seed % 2 else max(2, h // 8), slots)
+    extra = rng.random((slots, 2)) < (0.4, 0.1)
+    cats[:, 1:] = np.where(extra, rng.integers(0, h, (slots, 2)), -1)
+    valid = rng.random(slots) < rng.uniform(0.05, 0.9)
+    used, matched = greedy_matching_slots(
+        jnp.asarray(cats), jnp.asarray(valid), h
+    )
+    want_used, want_matched = _greedy_matching_loop(cats, valid, h)
+    np.testing.assert_array_equal(np.asarray(used), want_used)
+    np.testing.assert_array_equal(np.asarray(matched), want_matched)
