@@ -822,8 +822,8 @@ def _bench(quick: bool, num_shards: int | None = None) -> dict:
     # drop the Chrome trace artifact LAST: the mixed-workload section is
     # the one that produces every span kind (submit -> worker_ingest ->
     # publish on the ingest side, query_batch -> ... -> solve ->
-    # device_sync on the read side), and the ring buffer keeps the newest
-    # spans under overload
+    # solve.pack/h2d/run/value on the read side), and the ring buffer
+    # keeps the newest spans under overload
     trace_path = _JSON_PATH.replace(".json", ".trace.json")
     obs.dump_trace(trace_path)
     steady.close()

@@ -119,6 +119,15 @@ def partition_arrays(
     return cats1, caps_b
 
 
+def to_device(*host) -> list:
+    """``jnp.asarray`` of each argument of a jit solver launch, inside the
+    ``solve.h2d`` span whose ``bytes`` arg sums the host arrays copied.
+    Nothing waits here: a copy still in flight finishes in ``solve.run``."""
+    nbytes = sum(a.nbytes for a in host if isinstance(a, np.ndarray))
+    with obs.span("solve.h2d", cat="query", bytes=nbytes):
+        return [jnp.asarray(a) for a in host]
+
+
 # --------------------------------------------------------------------------
 # uniform / partition: counts-based feasibility (historical fast path)
 # --------------------------------------------------------------------------
@@ -390,47 +399,34 @@ class JitSumBatchEngine(SolverEngine):
     ) -> list[EngineSolution]:
         Bb = bucket_pow2(len(specs))
         kmax = bucket_pow2(max((s.k for s in specs), default=1))
-        allow_b, ks, gammas = pad_query_arrays(ctx, specs, Bb)
-
-        if ctx.spec.kind == "transversal":
-            oh = cats_onehot(ctx.cats, ctx.spec.num_categories)
+        tv = ctx.spec.kind == "transversal"
+        with obs.span("solve.pack", cat="query", B=Bb, kmax=kmax, m=ctx.size):
+            allow_b, ks, gammas = pad_query_arrays(ctx, specs, Bb)
+            if tv:
+                matroid = (cats_onehot(ctx.cats, ctx.spec.num_categories),)
+            else:
+                matroid = partition_arrays(ctx, specs, Bb)
+        args = to_device(ctx.D, *matroid, allow_b, ks, gammas)
+        fn, key = ((solve_sum_batch_transversal, "jit_sum_tv") if tv
+                   else (solve_sum_batch, "jit_sum"))
+        with obs.span("solve.run", cat="query"):
             with obs.compile_region(
-                f"solve[jit_sum_tv B={Bb} kmax={kmax} m={ctx.size}]"
+                f"solve[{key} B={Bb} kmax={kmax} m={ctx.size}]"
             ):
-                sel, nsel, _div = solve_sum_batch_transversal(
-                    jnp.asarray(ctx.D),
-                    jnp.asarray(oh),
-                    jnp.asarray(allow_b),
-                    jnp.asarray(ks),
-                    jnp.asarray(gammas),
-                    kmax=kmax,
-                )
-        else:
-            cats1, caps_b = partition_arrays(ctx, specs, Bb)
-            with obs.compile_region(
-                f"solve[jit_sum B={Bb} kmax={kmax} m={ctx.size}]"
-            ):
-                sel, nsel, _div = solve_sum_batch(
-                    jnp.asarray(ctx.D),
-                    jnp.asarray(cats1),
-                    jnp.asarray(caps_b),
-                    jnp.asarray(allow_b),
-                    jnp.asarray(ks),
-                    jnp.asarray(gammas),
-                    kmax=kmax,
-                )
-
-        sel, nsel = np.asarray(sel), np.asarray(nsel)
+                sel, nsel, _div = fn(*args, kmax=kmax)
+            sel, nsel = np.asarray(sel), np.asarray(nsel)
         out = []
-        for i, s in enumerate(specs):
-            loc = sel[i, : nsel[i]].astype(np.int64)
-            # the jit solver accumulates its objective in f32; the indices
-            # are what it decided on — report the canonical f64 value
-            out.append(
-                EngineSolution(
-                    local_indices=loc,
-                    value=selection_value(ctx.D, loc, s.variant),
-                    engine=self.name,
+        with obs.span("solve.value", cat="query", n=len(specs)):
+            for i, s in enumerate(specs):
+                loc = sel[i, : nsel[i]].astype(np.int64)
+                # the jit solver accumulates its objective in f32; the
+                # indices are what it decided on — report the canonical
+                # f64 value
+                out.append(
+                    EngineSolution(
+                        local_indices=loc,
+                        value=selection_value(ctx.D, loc, s.variant),
+                        engine=self.name,
+                    )
                 )
-            )
         return out

@@ -47,7 +47,12 @@ from .base import (
     SolverEngine,
     selection_value,
 )
-from .jit_sum import _solve_sum_one, bucket_pow2, jit_cell_eligible
+from .jit_sum import (
+    _solve_sum_one,
+    bucket_pow2,
+    jit_cell_eligible,
+    to_device,
+)
 
 # one tenant lane of a stacked solve: (context, specs routed to it)
 Lane = tuple[SolveContext, Sequence[SolveSpec]]
@@ -136,57 +141,54 @@ def solve_stacked(lanes: Sequence[Lane]) -> list[list[EngineSolution]]:
         for ctx, _specs in lanes
     ]
     hmax = max(hs)
-    # padding lanes keep a zero matrix and k=0 rows: the row solver
-    # no-ops on them exactly like the pow-2 padding rows it already has
-    Ds = np.zeros((Tb, m, m), dtype)
-    cats_s = np.zeros((Tb, m), np.int32)
-    caps = np.full((Tb, Bt, hmax), m + 1, np.int32)  # padding: uncapped
-    allow = np.zeros((Tb, Bt, m), bool)
-    ks = np.zeros((Tb, Bt), np.int32)
-    gammas = np.zeros((Tb, Bt), np.float32)
-    for t, (ctx, specs) in enumerate(lanes):
-        Ds[t] = ctx.D
-        if ctx.spec.kind == "partition":
-            cats_s[t] = np.asarray(ctx.cats[:, 0], np.int32)
-            default_caps = ctx.caps
-        else:  # uniform: one pseudo-category nobody caps
-            default_caps = None
-        h = hs[t]
-        for i, s in enumerate(specs):
-            allow[t, i] = s.allow_mask(m)
-            ks[t, i] = s.k
-            gammas[t, i] = s.gamma
-            if s.caps is not None:
-                caps[t, i, :h] = np.asarray(s.caps, np.int32)
-            elif default_caps is not None:
-                caps[t, i, :h] = default_caps
-    with obs.compile_region(
-        f"solve[jit_sum_stacked T={Tb} B={Bt} kmax={kmax} m={m}]"
-    ):
-        sel, nsel, _div = solve_sum_batch_stacked(
-            jnp.asarray(Ds),
-            jnp.asarray(cats_s),
-            jnp.asarray(caps),
-            jnp.asarray(allow),
-            jnp.asarray(ks),
-            jnp.asarray(gammas),
-            kmax=kmax,
-        )
-    sel, nsel = np.asarray(sel), np.asarray(nsel)
+    with obs.span("solve.pack", cat="query", T=Tb, B=Bt, kmax=kmax, m=m):
+        # padding lanes keep a zero matrix and k=0 rows: the row solver
+        # no-ops on them exactly like the pow-2 padding rows it already has
+        Ds = np.zeros((Tb, m, m), dtype)
+        cats_s = np.zeros((Tb, m), np.int32)
+        caps = np.full((Tb, Bt, hmax), m + 1, np.int32)  # padding: uncapped
+        allow = np.zeros((Tb, Bt, m), bool)
+        ks = np.zeros((Tb, Bt), np.int32)
+        gammas = np.zeros((Tb, Bt), np.float32)
+        for t, (ctx, specs) in enumerate(lanes):
+            Ds[t] = ctx.D
+            if ctx.spec.kind == "partition":
+                cats_s[t] = np.asarray(ctx.cats[:, 0], np.int32)
+                default_caps = ctx.caps
+            else:  # uniform: one pseudo-category nobody caps
+                default_caps = None
+            h = hs[t]
+            for i, s in enumerate(specs):
+                allow[t, i] = s.allow_mask(m)
+                ks[t, i] = s.k
+                gammas[t, i] = s.gamma
+                if s.caps is not None:
+                    caps[t, i, :h] = np.asarray(s.caps, np.int32)
+                elif default_caps is not None:
+                    caps[t, i, :h] = default_caps
+    args = to_device(Ds, cats_s, caps, allow, ks, gammas)
+    with obs.span("solve.run", cat="query"):
+        with obs.compile_region(
+            f"solve[jit_sum_stacked T={Tb} B={Bt} kmax={kmax} m={m}]"
+        ):
+            sel, nsel, _div = solve_sum_batch_stacked(*args, kmax=kmax)
+        sel, nsel = np.asarray(sel), np.asarray(nsel)
     out: list[list[EngineSolution]] = []
-    for t, (ctx, specs) in enumerate(lanes):
-        sols = []
-        for i, s in enumerate(specs):
-            loc = sel[t, i, : nsel[t, i]].astype(np.int64)
-            # same contract as the per-tenant engine: the f32 objective
-            # the kernel accumulated is discarded, the canonical f64
-            # value is recomputed from the indices it decided on
-            sols.append(
-                EngineSolution(
-                    local_indices=loc,
-                    value=selection_value(ctx.D, loc, s.variant),
-                    engine="jit_sum",
+    with obs.span("solve.value", cat="query",
+                  n=sum(len(specs) for _ctx, specs in lanes)):
+        for t, (ctx, specs) in enumerate(lanes):
+            sols = []
+            for i, s in enumerate(specs):
+                loc = sel[t, i, : nsel[t, i]].astype(np.int64)
+                # same contract as the per-tenant engine: the f32 objective
+                # the kernel accumulated is discarded, the canonical f64
+                # value is recomputed from the indices it decided on
+                sols.append(
+                    EngineSolution(
+                        local_indices=loc,
+                        value=selection_value(ctx.D, loc, s.variant),
+                        engine="jit_sum",
+                    )
                 )
-            )
-        out.append(sols)
+            out.append(sols)
     return out

@@ -12,20 +12,22 @@ Three pillars, one import:
   jit trace;
 * **tracing** — ``span()`` context managers with per-request trace IDs
   propagated from ``QueryFrontend.query_batch`` down through tenant
-  resolution, epoch acquire, cache build, engine solve, and device sync
-  (and across threads from ``submit`` to the ingest worker), recorded in
-  a lock-free ring buffer and exported as Chrome ``trace_event`` JSON
-  (``dump_trace(path)`` -> chrome://tracing / ui.perfetto.dev);
-* **jaxprof** — ``named_scope`` (the sanctioned *in-trace* annotation),
+  resolution, epoch acquire, cache build, engine solve and its phases
+  (``solve.pack``/``h2d``/``run``/``value``) (and across threads from
+  ``submit`` to the ingest worker), each span naming its parent, recorded
+  in a lock-free ring buffer, annotated for ``jax.profiler`` (the host
+  plane of a profiler trace shows them), and exported as Chrome
+  ``trace_event`` JSON (``dump_trace(path)`` -> chrome://tracing /
+  ui.perfetto.dev);
+* **jaxprof** — ``named_scope`` (the sanctioned *in-trace* annotation) and
   ``compile_region``/``RecompileWatch`` turning XLA recompiles into a
   per-bucketed-shape counter (the ``steady_state_recompiles == 0`` bench
-  gate), and opt-in ``jax.profiler`` capture (``profiler_trace``).
+  gate).
 
 Module-level conveniences operate on the process-global defaults;
 every component also accepts explicit ``registry=``/buffer instances so
 tests can count in isolation. ``set_enabled(False)`` turns the whole
-layer into a few attribute loads per call — the A/B the serve bench
-records as ``obs_overhead``.
+layer into a few attribute loads per call.
 """
 from __future__ import annotations
 
@@ -35,7 +37,6 @@ from .export import (
     dump_metrics,
     metrics_snapshot,
     observability_report,
-    write_chrome_trace,
     write_metrics_jsonl,
 )
 from .jaxprof import (
@@ -45,7 +46,6 @@ from .jaxprof import (
     compile_region,
     current_compile_region,
     named_scope,
-    profiler_trace,
     recompile_watch,
 )
 from .metrics import (
@@ -77,9 +77,8 @@ __all__ = [
     "current_compile_region", "current_trace_id", "default_buffer",
     "default_registry", "dump_metrics", "dump_trace", "gauge", "histogram",
     "metrics_snapshot", "named_scope", "new_trace_id",
-    "observability_report", "profiler_trace", "recompile_watch", "reset",
-    "resume_trace", "set_enabled", "span", "trace", "write_chrome_trace",
-    "write_metrics_jsonl",
+    "observability_report", "recompile_watch", "reset", "resume_trace",
+    "set_enabled", "span", "trace", "write_metrics_jsonl",
 ]
 
 

@@ -3,8 +3,8 @@
 The registry/tracing modules own their own serialization
 (``MetricsRegistry.write_jsonl``/``dump``, ``TraceBuffer.dump``); this
 module is the batteries-included layer the bench, the example, and CI
-use: grab *everything* (metrics + recompile census + trace) in one call,
-against the process defaults or explicit instances.
+use: grab the metrics and the recompile census in one call, against the
+process defaults or explicit instances (the trace: ``obs.dump_trace``).
 """
 from __future__ import annotations
 
@@ -12,7 +12,6 @@ from typing import Optional
 
 from .jaxprof import RecompileWatch, recompile_watch
 from .metrics import MetricsRegistry, default_registry
-from .tracing import TraceBuffer, default_buffer
 
 
 def metrics_snapshot(registry: Optional[MetricsRegistry] = None) -> dict:
@@ -27,11 +26,6 @@ def write_metrics_jsonl(path: str,
 
 def dump_metrics(registry: Optional[MetricsRegistry] = None, stream=None):
     (registry or default_registry()).dump(stream)
-
-
-def write_chrome_trace(path: str,
-                       buffer: Optional[TraceBuffer] = None) -> str:
-    return (buffer or default_buffer()).dump(path)
 
 
 def observability_report(

@@ -1,5 +1,5 @@
-"""JAX-aware profiling hooks: named scopes, a recompile counter keyed by
-bucketed shape, and opt-in ``jax.profiler`` trace capture.
+"""JAX-aware profiling hooks: named scopes and a recompile counter keyed
+by bucketed shape.
 
 Recompile semantics: every XLA backend compile in the process fires
 ``/jax/core/compile/backend_compile_duration`` through ``jax.monitoring``.
@@ -24,17 +24,11 @@ annotation: it tags HLO ops with their source region so profiler traces
 and compiled-module dumps read as ``dmmc/blocked_scan``,
 ``dmmc/precheck``, ``solver/jit_sum`` instead of fusion soup. It is
 metadata only — safe under jit/vmap/scan, zero runtime cost.
-
-``profiler_trace`` wraps ``jax.profiler.start_trace/stop_trace`` as an
-opt-in context manager (explicit ``enabled=True`` or the
-``REPRO_OBS_PROFILE=dir`` environment knob) that never lets profiler
-failures take down serving.
 """
 from __future__ import annotations
 
 import contextlib
 import contextvars
-import os
 import threading
 from typing import Optional
 
@@ -154,33 +148,3 @@ def recompile_watch() -> RecompileWatch:
                 _default_watch = RecompileWatch()
     return _default_watch
 
-
-@contextlib.contextmanager
-def profiler_trace(logdir: Optional[str] = None, *,
-                   enabled: Optional[bool] = None):
-    """Opt-in ``jax.profiler`` capture around a region (ingest/solve
-    sections in the bench). Default resolves from ``REPRO_OBS_PROFILE``:
-    unset -> disabled; set -> enabled, its value the log directory unless
-    ``logdir`` overrides. Yields True iff a capture is running; profiler
-    errors (double-start, unsupported backend) disable the capture
-    rather than failing the caller."""
-    env = os.environ.get("REPRO_OBS_PROFILE", "")
-    on = bool(env) if enabled is None else enabled
-    where = logdir or env or "/tmp/repro-jax-trace"
-    if not on:
-        yield False
-        return
-    started = False
-    try:
-        jax.profiler.start_trace(where)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield started
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:  # pragma: no cover - defensive
-                pass

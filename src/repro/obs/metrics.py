@@ -31,8 +31,7 @@ worst kind of observability bug (a counter that reads 1 forever). The
 guard turns that into a loud ``TracerLeakError`` at trace time, which is
 what ``tests/test_obs.py`` pins. Disabling a registry (``enabled=False``)
 short-circuits mutations *before* the guard, so a disabled registry is a
-couple of attribute loads per call — that is the A/B the serve bench
-measures as ``obs_overhead``.
+couple of attribute loads per call.
 """
 from __future__ import annotations
 

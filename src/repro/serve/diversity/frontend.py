@@ -539,24 +539,20 @@ class QueryFrontend:
                     sols = eng.solve_batch(
                         ctx, [specs[i] for i in idxs]
                     )
-                # materializing local_indices/value blocks on the device:
-                # the sync cost rides in this span, and the solve latency
-                # histogram (below) includes it — what the caller feels
-                with obs.span("device_sync", cat="query", engine=name):
-                    for i, sol in zip(idxs, sols):
-                        loc = np.asarray(sol.local_indices, np.int64)
-                        results[i] = QueryResult(
-                            indices=entry.src_idx[loc],
-                            local_indices=loc,
-                            diversity=sol.value,
-                            variant=queries[i].variant,
-                            engine=sol.engine,
-                            coreset_size=entry.size,
-                            from_cache=cached,
-                            epoch=snap.epoch,
-                            tenant=t.name,
-                            degraded=i in degraded_ix,
-                        )
+                for i, sol in zip(idxs, sols):
+                    loc = np.asarray(sol.local_indices, np.int64)
+                    results[i] = QueryResult(
+                        indices=entry.src_idx[loc],
+                        local_indices=loc,
+                        diversity=sol.value,
+                        variant=queries[i].variant,
+                        engine=sol.engine,
+                        coreset_size=entry.size,
+                        from_cache=cached,
+                        epoch=snap.epoch,
+                        tenant=t.name,
+                        degraded=i in degraded_ix,
+                    )
                 dt = time.perf_counter() - t1
                 reg.histogram(
                     "serve.solve.latency_s", tenant=t.name, engine=name
@@ -718,21 +714,20 @@ class QueryFrontend:
                     }),
                 ):
                     sols = eng.solve_batch(ctx, mspecs)
-                with obs.span("device_sync", cat="query", engine=name):
-                    for (c, i), sol in zip(items, sols):
-                        loc = np.asarray(sol.local_indices, np.int64)
-                        c.results[i] = QueryResult(
-                            indices=entry.src_idx[loc],
-                            local_indices=loc,
-                            diversity=sol.value,
-                            variant=c.queries[i].variant,
-                            engine=sol.engine,
-                            coreset_size=entry.size,
-                            from_cache=c.from_cache,
-                            epoch=snap.epoch,
-                            tenant=t.name,
-                            degraded=i in c.degraded,
-                        )
+                for (c, i), sol in zip(items, sols):
+                    loc = np.asarray(sol.local_indices, np.int64)
+                    c.results[i] = QueryResult(
+                        indices=entry.src_idx[loc],
+                        local_indices=loc,
+                        diversity=sol.value,
+                        variant=c.queries[i].variant,
+                        engine=sol.engine,
+                        coreset_size=entry.size,
+                        from_cache=c.from_cache,
+                        epoch=snap.epoch,
+                        tenant=t.name,
+                        degraded=i in c.degraded,
+                    )
                 dt = time.perf_counter() - t1
                 reg.histogram(
                     "serve.solve.latency_s", tenant=t.name, engine=name
@@ -967,11 +962,8 @@ class QueryFrontend:
                         }),
                     ):
                         lane_sols = eng.solve_batch_stacked(lane_args)
-                    with obs.span(
-                        "device_sync", cat="query", engine=name
-                    ):
-                        for lane_i, sols in zip(lis, lane_sols):
-                            _fan(lane_i, per_lane[lane_i], sols)
+                    for lane_i, sols in zip(lis, lane_sols):
+                        _fan(lane_i, per_lane[lane_i], sols)
                     dt = time.perf_counter() - t1
                     reg.counter("serve.coalesce.stacked_solves").inc()
                     reg.counter(
@@ -1010,10 +1002,7 @@ class QueryFrontend:
                         coalesced_calls=len({id(c) for c, _ in li}),
                     ):
                         sols = eng.solve_batch(ctx, lspecs)
-                    with obs.span(
-                        "device_sync", cat="query", engine=name
-                    ):
-                        _fan(lane_i, li, sols)
+                    _fan(lane_i, li, sols)
                     dt = time.perf_counter() - t1
                     reg.histogram(
                         "serve.solve.latency_s", tenant=lt.name,

@@ -232,11 +232,14 @@ def test_trace_id_propagates_through_query_batch_spans(rng):
     rt.ingest(P, C)
     buf = obs.default_buffer()
     buf.clear()
-    fe.query_batch([DiversityQuery(k=4), DiversityQuery(k=3)])
+    # jit_sum: the engine whose launch records the solve phase spans
+    fe.query_batch([DiversityQuery(k=4), DiversityQuery(k=3)],
+                   engine="jit_sum")
     spans = buf.drain()
     names = {s.name for s in spans}
     assert {"query_batch", "resolve_tenant", "acquire_epoch",
-            "cache_entry", "solve", "device_sync"} <= names
+            "cache_entry", "solve", "solve.pack", "solve.h2d", "solve.run",
+            "solve.value"} <= names
     ids = {s.trace_id for s in spans}
     assert len(ids) == 1 and None not in ids  # one request, one trace
     # a second request gets a DIFFERENT trace id
@@ -289,6 +292,188 @@ def test_ring_buffer_overwrites_oldest():
             pass
     got = [s.name for s in buf.drain()]
     assert got == ["s6", "s7", "s8", "s9"]  # newest capacity survive
+
+
+def test_span_tree_links_children_to_parents():
+    buf = obs.TraceBuffer(capacity=16)
+    with obs.trace():
+        with buf.span("outer"):
+            with buf.span("a"):
+                pass
+            with buf.span("b"):
+                with buf.span("b1"):
+                    pass
+            with obs.trace():  # joins the open trace, under "outer"
+                with buf.span("joined"):
+                    pass
+    with obs.trace():
+        with buf.span("next"):
+            pass
+    with buf.span("host"):
+        with obs.trace():
+            with buf.span("fresh"):
+                pass
+        with obs.resume_trace("feedfacefeedface"):
+            with buf.span("resumed"):
+                pass
+        with buf.span("after"):
+            pass
+    by = {s.name: s for s in buf.drain()}
+    assert len({s.span_id for s in by.values()}) == len(by)
+    outer = by["outer"]
+    assert outer.parent_id is None
+    assert by["a"].parent_id == by["b"].parent_id == outer.span_id
+    assert by["b1"].parent_id == by["b"].span_id
+    assert by["joined"].parent_id == outer.span_id
+    assert by["joined"].trace_id == outer.trace_id
+    # a new trace starts a new root; so does a resumed one
+    assert by["next"].parent_id is None
+    assert by["next"].trace_id != outer.trace_id
+    assert by["fresh"].parent_id is None and by["resumed"].parent_id is None
+    assert by["resumed"].trace_id == "feedfacefeedface"
+    # ...and leaving them restores the enclosing parent
+    assert by["after"].parent_id == by["host"].span_id
+    evs = {e["name"]: e for e in buf.chrome_trace()["traceEvents"]}
+    for name, rec in by.items():
+        assert evs[name]["args"]["span_id"] == rec.span_id
+        assert evs[name]["args"]["parent_id"] == rec.parent_id
+
+
+def _phases_under_solve(spans):
+    """{phase name: record} of the one launch under the one ``solve``
+    span, after checking each phase is a child of it."""
+    solve = [s for s in spans if s.name == "solve"]
+    assert len(solve) == 1
+    phases = {s.name: s for s in spans if s.name.startswith("solve.")}
+    assert set(phases) == {"solve.pack", "solve.h2d", "solve.run",
+                           "solve.value"}
+    for ph in phases.values():
+        assert ph.parent_id == solve[0].span_id
+        assert ph.trace_id == solve[0].trace_id
+    order = sorted(phases.values(), key=lambda s: s.ts_us)
+    assert [s.name for s in order] == ["solve.pack", "solve.h2d",
+                                       "solve.run", "solve.value"]
+    return phases
+
+
+def test_jit_sum_launch_records_phase_spans_under_solve(rng):
+    rt = make_runtime()
+    fe = QueryFrontend(rt)
+    P, C = feed(rng, 128)
+    rt.ingest(P, C)
+    buf = obs.default_buffer()
+    buf.clear()
+    fe.query_batch([DiversityQuery(k=4), DiversityQuery(k=3)],
+                   engine="jit_sum")
+    ph = _phases_under_solve(buf.drain())
+    B, kmax, m = (ph["solve.pack"].args[a] for a in ("B", "kmax", "m"))
+    assert (B, kmax) == (2, 4)
+    assert ph["solve.value"].args["n"] == 2
+    h = SPEC.num_categories
+    # D (float32), cats (m,) and caps (B, h) int32, allow (B, m) bool,
+    # ks int32 and gammas float32 (B,)
+    assert ph["solve.h2d"].args["bytes"] == (
+        m * m * 4 + m * 4 + B * h * 4 + B * m + B * 4 + B * 4)
+
+
+def _launch_partition(rng, m):
+    from repro.core.solvers import SolveContext, SolveSpec
+    from repro.core.solvers.jit_sum import JitSumBatchEngine
+
+    ctx = SolveContext(
+        D=rng.random((m, m)).astype(np.float32), spec=SPEC,
+        cats=rng.integers(0, 4, (m, 1)).astype(np.int32), caps=CAPS)
+    JitSumBatchEngine().solve_batch(ctx, [SolveSpec(k=3)] * 3)
+    # B 4 (pow-2 of 3): D, cats (m,), caps (4, 4), allow, ks, gammas
+    return m * m * 4 + m * 4 + 4 * 4 * 4 + 4 * m + 4 * 4 + 4 * 4
+
+
+def _launch_transversal(rng, m):
+    from repro.core.solvers import SolveContext, SolveSpec
+    from repro.core.solvers.jit_sum import JitSumBatchEngine
+
+    cats = np.full((m, 2), -1, np.int32)
+    cats[:, 0] = rng.integers(0, 4, m)
+    ctx = SolveContext(
+        D=rng.random((m, m)).astype(np.float32),
+        spec=MatroidSpec("transversal", num_categories=4, gamma=2),
+        cats=cats)
+    JitSumBatchEngine().solve_batch(ctx, [SolveSpec(k=3)])
+    # B 1: D, the (m, 4) bool incidence, allow, ks, gammas
+    return m * m * 4 + m * 4 + m + 4 + 4
+
+
+def _launch_stacked(rng, m):
+    from repro.core.solvers import SolveContext, SolveSpec
+    from repro.core.solvers.stacked import solve_stacked
+
+    lanes = [(SolveContext(
+        D=rng.random((m, m)).astype(np.float32), spec=SPEC,
+        cats=rng.integers(0, 4, (m, 1)).astype(np.int32), caps=CAPS),
+        [SolveSpec(k=2)] * b) for b in (1, 2, 1)]
+    solve_stacked(lanes)
+    # T 4, B 2: Ds (4, m, m), cats (4, m), caps (4, 2, 4), allow (4, 2, m),
+    # ks and gammas (4, 2)
+    return (4 * m * m * 4 + 4 * m * 4 + 4 * 2 * 4 * 4 + 4 * 2 * m
+            + 4 * 2 * 4 + 4 * 2 * 4)
+
+
+@pytest.mark.parametrize("launch", [_launch_partition, _launch_transversal,
+                                    _launch_stacked],
+                         ids=["partition", "transversal", "stacked"])
+def test_every_jit_launch_path_records_its_phases(rng, launch):
+    buf = obs.default_buffer()
+    buf.clear()
+    with obs.trace(), obs.span("solve", cat="query"):
+        want = launch(rng, 24)
+    ph = _phases_under_solve(buf.drain())
+    assert ph["solve.h2d"].args["bytes"] == want
+
+
+def test_spans_land_nested_on_the_profiler_host_plane(rng, tmp_path):
+    from jax.profiler import ProfileData
+
+    rt = make_runtime()
+    fe = QueryFrontend(rt)
+    P, C = feed(rng, 128)
+    rt.ingest(P, C)
+    q = [DiversityQuery(k=4)]
+    fe.query_batch(q, engine="jit_sum")  # compile outside the trace
+    buf = obs.default_buffer()
+    buf.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        # the harness's anchor: the window annotation's start, stamped on
+        # the span clock
+        with jax.profiler.TraceAnnotation("bench/window"):
+            anchor_us = obs.tracing.now_us()
+            fe.query_batch(q, engine="jit_sum")
+    finally:
+        jax.profiler.stop_trace()
+    ring = buf.drain()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    host = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for p in ProfileData.from_file(str(path)).planes
+            if p.name.startswith("/host")
+            for line in p.lines for ev in line.events]
+    (w0,) = [a for name, a, _b in host if name == "bench/window"]
+    off_ns = w0 - anchor_us * 1e3
+    names = ["query_batch", "solve", "solve.pack", "solve.h2d",
+             "solve.run", "solve.value"]
+    got = {}
+    for name in names:
+        (rec,) = [s for s in ring if s.name == name]
+        (ev,) = [e for e in host if e[0] == name]
+        # the ring and the trace file agree on the span's start
+        assert abs(ev[1] - (rec.ts_us * 1e3 + off_ns)) < 1e6, name
+        got[name] = ev
+    # nested as in the ring: each phase inside solve, solve inside the batch
+    for child, parent in [("solve", "query_batch")] + [
+            (n, "solve") for n in names[2:]]:
+        assert got[parent][1] <= got[child][1]
+        assert got[child][2] <= got[parent][2]
 
 
 # ---------------------------------------------------------------------------
