@@ -8,6 +8,14 @@ search, mirroring ``solvers.local_search.local_search_sum`` step for step
 kept in insertion order) so the fast path lands on the same local optimum
 as the host solver on the same matrix.
 
+The swap itself (new selection, counts or matching, and the full ``D @ X``
+row-sum recompute) runs under ``streaming._cond_once``, a single-trip
+``while_loop``: under vmap a ``lax.cond`` lowers to a select that would run
+the swap, and read all of D, for every candidate v of every sweep. With the
+loop it runs only on the candidates where some row of the batch swaps.
+Each launch also returns the number of swaps its rows made (summed) and the
+trips of its sweep loop, for the ``solve.run`` span.
+
 Matroid feasibility inside the greedy/swap loops comes in two flavours,
 chosen statically per matroid kind:
 
@@ -35,6 +43,7 @@ import numpy as np
 
 from ... import obs
 from ..diversity import Variant
+from ..streaming import _cond_once
 from .base import (
     EngineSolution,
     SolveContext,
@@ -128,6 +137,18 @@ def to_device(*host) -> list:
         return [jnp.asarray(a) for a in host]
 
 
+def vmap_rows(row_solver, D, matroid, *rows):
+    """``row_solver`` vmapped over the batch's rows, with D and the shared
+    matroid array unmapped. Returns (sel, nsel, div) per row, then two
+    int32 scalars: ``swaps``, the swaps all rows made, and ``sweeps``, the
+    trips of the batched sweep loop (the most any row made)."""
+    in_axes = (None, None) + (0,) * len(rows)
+    sel, nsel, div, swaps, sweeps = jax.vmap(row_solver, in_axes=in_axes)(
+        D, matroid, *rows
+    )
+    return sel, nsel, div, jnp.sum(swaps), jnp.max(sweeps)
+
+
 # --------------------------------------------------------------------------
 # uniform / partition: counts-based feasibility (historical fast path)
 # --------------------------------------------------------------------------
@@ -178,7 +199,7 @@ def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
     slots = jnp.arange(kmax, dtype=jnp.int32)
 
     def v_body(v, st):
-        sel, selmask, counts, rowX, div, improved = st
+        sel, selmask, counts, rowX, div, swaps, improved = st
         u = jnp.maximum(sel, 0)  # (kmax,) slot -> local id (garbage past k)
         # div(X - u + v) = div - row[u] + dv - d(u, v)   (host's identity)
         new_div = div - rowX[u] + rowX[v] - D[u, v]
@@ -194,7 +215,7 @@ def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
         ui = jnp.argmax(improving)  # first improving u in X order
 
         def do_swap(st):
-            sel, selmask, counts, rowX, div, improved = st
+            sel, selmask, counts, rowX, div, swaps, improved = st
             uold = sel[ui]
             # host order: X = [w for w in X if w != u] + [v]
             src = jnp.where(slots >= ui, jnp.minimum(slots + 1, kmax - 1), slots)
@@ -202,9 +223,9 @@ def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
             selmask2 = selmask.at[uold].set(False).at[v].set(True)
             counts2 = counts.at[cats[uold]].add(-1).at[cat_v].add(1)
             rowX2 = _dot(D, selmask2.astype(jnp.float32))
-            return sel2, selmask2, counts2, rowX2, new_div[ui], True
+            return sel2, selmask2, counts2, rowX2, new_div[ui], swaps + 1, True
 
-        return jax.lax.cond(any_imp, do_swap, lambda s: s, st)
+        return _cond_once(any_imp, do_swap, st)
 
     def sweep_cond(carry):
         st, sweeps = carry
@@ -217,11 +238,12 @@ def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
         return st, sweeps + 1
 
     rowX0 = _dot(D, selm_f)
-    ls0 = ((sel, selmask, counts, rowX0, div0, nsel == k), jnp.int32(0))
-    (sel, selmask, counts, _rowX, div, _imp), _ = jax.lax.while_loop(
-        sweep_cond, sweep_body, ls0
+    ls0 = ((sel, selmask, counts, rowX0, div0, jnp.int32(0), nsel == k),
+           jnp.int32(0))
+    (sel, selmask, counts, _rowX, div, swaps, _imp), sweeps = (
+        jax.lax.while_loop(sweep_cond, sweep_body, ls0)
     )
-    return sel, nsel, div
+    return sel, nsel, div, swaps, sweeps
 
 
 @functools.partial(jax.jit, static_argnames=("kmax", "max_sweeps"))
@@ -237,12 +259,11 @@ def solve_sum_batch(
     max_sweeps: int = 64,
 ):
     """Batch of sum-DMMC queries on one matrix (uniform/partition).
-    Returns (sel (B, kmax) local ids -1-padded, nsel (B,), div (B,))."""
+    Returns (sel (B, kmax) local ids -1-padded, nsel (B,), div (B,),
+    swaps, sweeps) as ``vmap_rows`` counts them."""
     f = functools.partial(_solve_sum_one, kmax=kmax, max_sweeps=max_sweeps)
     with jax.named_scope("solver/jit_sum"):
-        return jax.vmap(f, in_axes=(None, None, 0, 0, 0, 0))(
-            D, cats, caps, allow, ks, gammas
-        )
+        return vmap_rows(f, D, cats, caps, allow, ks, gammas)
 
 
 # --------------------------------------------------------------------------
@@ -298,7 +319,7 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
     slots = jnp.arange(kmax, dtype=jnp.int32)
 
     def v_body(v, st):
-        sel, selmask, ms_pt, rowX, div, improved = st
+        sel, selmask, ms_pt, rowX, div, swaps, improved = st
         u = jnp.maximum(sel, 0)
         new_div = div - rowX[u] + rowX[v] - D[u, v]
         ok_swap = swap_feasible(oh, ms_pt, sel, v)  # (kmax,) exact
@@ -312,7 +333,7 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
         ui = jnp.argmax(improving)
 
         def do_swap(st):
-            sel, selmask, ms_pt, rowX, div, improved = st
+            sel, selmask, ms_pt, rowX, div, swaps, improved = st
             uold = sel[ui]
             src = jnp.where(slots >= ui, jnp.minimum(slots + 1, kmax - 1), slots)
             sel2 = sel[src].at[nsel - 1].set(v)
@@ -321,9 +342,9 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
             ms2 = jnp.where(ms_pt == uold, jnp.int32(-1), ms_pt)
             ms2 = augment(oh, ms2, v, kmax)
             rowX2 = _dot(D, selmask2.astype(jnp.float32))
-            return sel2, selmask2, ms2, rowX2, new_div[ui], True
+            return sel2, selmask2, ms2, rowX2, new_div[ui], swaps + 1, True
 
-        return jax.lax.cond(any_imp, do_swap, lambda s: s, st)
+        return _cond_once(any_imp, do_swap, st)
 
     def sweep_cond(carry):
         st, sweeps = carry
@@ -336,11 +357,12 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
         return st, sweeps + 1
 
     rowX0 = _dot(D, selm_f)
-    ls0 = ((sel, selmask, ms_pt, rowX0, div0, nsel == k), jnp.int32(0))
-    (sel, _selmask, _ms, _rowX, div, _imp), _ = jax.lax.while_loop(
-        sweep_cond, sweep_body, ls0
+    ls0 = ((sel, selmask, ms_pt, rowX0, div0, jnp.int32(0), nsel == k),
+           jnp.int32(0))
+    (sel, _selmask, _ms, _rowX, div, swaps, _imp), sweeps = (
+        jax.lax.while_loop(sweep_cond, sweep_body, ls0)
     )
-    return sel, nsel, div
+    return sel, nsel, div, swaps, sweeps
 
 
 @functools.partial(jax.jit, static_argnames=("kmax", "max_sweeps"))
@@ -355,12 +377,11 @@ def solve_sum_batch_transversal(
     max_sweeps: int = 64,
 ):
     """Batch of sum-DMMC queries under ONE transversal matroid.
-    Returns (sel (B, kmax) -1-padded, nsel (B,), div (B,))."""
+    Returns (sel (B, kmax) -1-padded, nsel (B,), div (B,), swaps, sweeps)
+    as ``vmap_rows`` counts them."""
     f = functools.partial(_solve_sum_one_tv, kmax=kmax, max_sweeps=max_sweeps)
     with jax.named_scope("solver/jit_sum_tv"):
-        return jax.vmap(f, in_axes=(None, None, 0, 0, 0))(
-            D, oh, allow, ks, gammas
-        )
+        return vmap_rows(f, D, oh, allow, ks, gammas)
 
 
 # --------------------------------------------------------------------------
@@ -409,12 +430,15 @@ class JitSumBatchEngine(SolverEngine):
         args = to_device(ctx.D, *matroid, allow_b, ks, gammas)
         fn, key = ((solve_sum_batch_transversal, "jit_sum_tv") if tv
                    else (solve_sum_batch, "jit_sum"))
-        with obs.span("solve.run", cat="query"):
+        with obs.span("solve.run", cat="query") as run:
             with obs.compile_region(
                 f"solve[{key} B={Bb} kmax={kmax} m={ctx.size}]"
             ):
-                sel, nsel, _div = fn(*args, kmax=kmax)
-            sel, nsel = np.asarray(sel), np.asarray(nsel)
+                sel, nsel, _div, swaps, sweeps = fn(*args, kmax=kmax)
+            sel, nsel, swaps, sweeps = jax.device_get(
+                (sel, nsel, swaps, sweeps)
+            )
+            run.update(swaps=int(swaps), sweeps=int(sweeps))
         out = []
         with obs.span("solve.value", cat="query", n=len(specs)):
             for i, s in enumerate(specs):

@@ -14,10 +14,11 @@ tenants.
 
 Bit-identity (the parity contract ``tests/test_stacked_solve.py`` pins):
 the stacked kernel is a ``lax.scan`` over tenant lanes whose body is the
-*unmodified* per-tenant row solver (``jit_sum._solve_sum_one``) vmapped
-with an unmapped ``(m, m)`` D — each scan step slices one tenant's
-matrix out of the batched leaf, so every matmul runs at the same shape
-and accumulation as the per-tenant dispatch. (A gather-form
+per-tenant row solver itself (``jit_sum._solve_sum_one``, whose swap runs
+only when some row of the lane swaps) vmapped by the same
+``jit_sum.vmap_rows``, with an unmapped ``(m, m)`` D — each scan step
+slices one tenant's matrix out of the batched leaf, so every matmul runs
+at the same shape and accumulation as the per-tenant dispatch. (A gather-form
 ``vmap(f(Ds[t], ...))`` was measurably NOT safe: the batched matmul
 accumulates in a different order and flips greedy argmax decisions on
 tie-heavy data.) The remaining freedom — the pow-2 row padding differing
@@ -52,6 +53,7 @@ from .jit_sum import (
     bucket_pow2,
     jit_cell_eligible,
     to_device,
+    vmap_rows,
 )
 
 # one tenant lane of a stacked solve: (context, specs routed to it)
@@ -82,7 +84,8 @@ def solve_sum_batch_stacked(
     max_sweeps: int = 64,
 ):
     """T tenant lanes of Bt sum-DMMC rows each, ONE dispatch.  Returns
-    (sel (T, Bt, kmax) -1-padded, nsel (T, Bt), div (T, Bt)).
+    (sel (T, Bt, kmax) -1-padded, nsel (T, Bt), div (T, Bt), swaps,
+    sweeps): the swaps of all rows, and the sweep-loop trips of all lanes.
 
     ``lax.scan`` (not an outer vmap) on purpose: inside each scan step
     the lane's D is a concrete (m, m) operand, so the inner vmapped
@@ -93,17 +96,13 @@ def solve_sum_batch_stacked(
     f = functools.partial(_solve_sum_one, kmax=kmax, max_sweeps=max_sweeps)
 
     def lane(carry, xs):
-        D, cats, caps_t, allow_t, ks_t, g_t = xs
-        out = jax.vmap(f, in_axes=(None, None, 0, 0, 0, 0))(
-            D, cats, caps_t, allow_t, ks_t, g_t
-        )
-        return carry, out
+        return carry, vmap_rows(f, *xs)
 
     with jax.named_scope("solver/jit_sum_stacked"):
-        _, outs = jax.lax.scan(
+        _, (sel, nsel, div, swaps, sweeps) = jax.lax.scan(
             lane, jnp.int32(0), (Ds, cats_s, caps, allow, ks, gammas)
         )
-    return outs
+    return sel, nsel, div, jnp.sum(swaps), jnp.sum(sweeps)
 
 
 def solve_stacked(lanes: Sequence[Lane]) -> list[list[EngineSolution]]:
@@ -167,12 +166,15 @@ def solve_stacked(lanes: Sequence[Lane]) -> list[list[EngineSolution]]:
                 elif default_caps is not None:
                     caps[t, i, :h] = default_caps
     args = to_device(Ds, cats_s, caps, allow, ks, gammas)
-    with obs.span("solve.run", cat="query"):
+    with obs.span("solve.run", cat="query") as run:
         with obs.compile_region(
             f"solve[jit_sum_stacked T={Tb} B={Bt} kmax={kmax} m={m}]"
         ):
-            sel, nsel, _div = solve_sum_batch_stacked(*args, kmax=kmax)
-        sel, nsel = np.asarray(sel), np.asarray(nsel)
+            sel, nsel, _div, swaps, sweeps = solve_sum_batch_stacked(
+                *args, kmax=kmax
+            )
+        sel, nsel, swaps, sweeps = jax.device_get((sel, nsel, swaps, sweeps))
+        run.update(swaps=int(swaps), sweeps=int(sweeps))
     out: list[list[EngineSolution]] = []
     with obs.span("solve.value", cat="query",
                   n=sum(len(specs) for _ctx, specs in lanes)):

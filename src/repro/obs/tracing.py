@@ -173,9 +173,10 @@ class TraceBuffer:
         """Time one host-side region, as a child of the innermost span
         open on this context, and annotate it for ``jax.profiler``;
         records on exit (exceptions included — a span that died still
-        shows its duration)."""
+        shows its duration). Yields the span's ``args`` dict: what the
+        body adds to it is recorded with the span."""
         if not self.enabled:
-            yield None
+            yield args
             return
         assert_host_side(f"span({name!r})")
         span_id = next(_span_ids)
@@ -185,7 +186,7 @@ class TraceBuffer:
             ts = now_us()
             t0 = time.perf_counter()
             try:
-                yield None
+                yield args
             finally:
                 dur_us = (time.perf_counter() - t0) * 1e6
                 _parent_id.reset(token)

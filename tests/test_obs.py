@@ -428,6 +428,7 @@ def test_every_jit_launch_path_records_its_phases(rng, launch):
         want = launch(rng, 24)
     ph = _phases_under_solve(buf.drain())
     assert ph["solve.h2d"].args["bytes"] == want
+    assert set(ph["solve.run"].args) == {"swaps", "sweeps"}
 
 
 def test_spans_land_nested_on_the_profiler_host_plane(rng, tmp_path):
