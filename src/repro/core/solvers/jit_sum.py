@@ -14,7 +14,9 @@ row-sum recompute) runs under ``streaming._cond_once``, a single-trip
 the swap, and read all of D, for every candidate v of every sweep. With the
 loop it runs only on the candidates where some row of the batch swaps.
 Each launch also returns the number of swaps its rows made (summed) and the
-trips of its sweep loop, for the ``solve.run`` span.
+trips of its sweep loop, for the ``solve.run`` span, which also records the
+candidate visits the device ran (every row of a vmapped sweep loop visits
+all m candidates for as many sweeps as the loop made) and the matroid kind.
 
 Matroid feasibility inside the greedy/swap loops comes in two flavours,
 chosen statically per matroid kind:
@@ -438,7 +440,9 @@ class JitSumBatchEngine(SolverEngine):
             sel, nsel, swaps, sweeps = jax.device_get(
                 (sel, nsel, swaps, sweeps)
             )
-            run.update(swaps=int(swaps), sweeps=int(sweeps))
+            run.update(swaps=int(swaps), sweeps=int(sweeps),
+                       visits=Bb * ctx.size * int(sweeps),
+                       matroid=ctx.spec.kind)
         out = []
         with obs.span("solve.value", cat="query", n=len(specs)):
             for i, s in enumerate(specs):

@@ -174,7 +174,11 @@ def solve_stacked(lanes: Sequence[Lane]) -> list[list[EngineSolution]]:
                 *args, kmax=kmax
             )
         sel, nsel, swaps, sweeps = jax.device_get((sel, nsel, swaps, sweeps))
-        run.update(swaps=int(swaps), sweeps=int(sweeps))
+        # each lane's loop runs its Bt rows over all m candidates per sweep
+        run.update(swaps=int(swaps), sweeps=int(sweeps),
+                   visits=Bt * m * int(sweeps),
+                   matroid=",".join(sorted({ctx.spec.kind
+                                            for ctx, _specs in lanes})))
     out: list[list[EngineSolution]] = []
     with obs.span("solve.value", cat="query",
                   n=sum(len(specs) for _ctx, specs in lanes)):

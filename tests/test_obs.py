@@ -428,7 +428,12 @@ def test_every_jit_launch_path_records_its_phases(rng, launch):
         want = launch(rng, 24)
     ph = _phases_under_solve(buf.drain())
     assert ph["solve.h2d"].args["bytes"] == want
-    assert set(ph["solve.run"].args) == {"swaps", "sweeps"}
+    run = ph["solve.run"].args
+    assert set(run) == {"swaps", "sweeps", "visits", "matroid"}
+    # every row of the launch visits all 24 candidates in each sweep
+    assert run["visits"] > 0 and run["visits"] % (24 * run["sweeps"]) == 0
+    assert run["matroid"] == ("transversal" if launch is _launch_transversal
+                              else "partition")
 
 
 def test_spans_land_nested_on_the_profiler_host_plane(rng, tmp_path):
