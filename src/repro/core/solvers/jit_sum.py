@@ -8,15 +8,23 @@ search, mirroring ``solvers.local_search.local_search_sum`` step for step
 kept in insertion order) so the fast path lands on the same local optimum
 as the host solver on the same matrix.
 
-The swap itself (new selection, counts or matching, and the full ``D @ X``
-row-sum recompute) runs under ``streaming._cond_once``, a single-trip
-``while_loop``: under vmap a ``lax.cond`` lowers to a select that would run
-the swap, and read all of D, for every candidate v of every sweep. With the
-loop it runs only on the candidates where some row of the batch swaps.
+The local search's state changes only on a swap, so one sweep tests a
+block of T candidates per trip of a ``while_loop`` (``_local_search``,
+shared by both feasibility families): every (u, v) swap value of the block
+with the host's arithmetic, then the first v with an improving, feasible
+u, and the first such u in X order. On a swap the next trip starts at
+v + 1, else after the block: the host's decisions in ~m/T + swaps trips a
+sweep instead of m. T is a power of two from the static shapes
+(``_block_size``). The swap itself (new selection, counts or matching, and
+the full ``D @ X`` row-sum recompute) runs under ``streaming._cond_once``,
+a single-trip ``while_loop``: under vmap a ``lax.cond`` lowers to a select
+that would run the swap, and read all of D, on every trip. With the loop
+it runs only on the trips where some row of the batch swaps.
 Each launch also returns the number of swaps its rows made (summed) and the
-trips of its sweep loop, for the ``solve.run`` span, which also records the
-candidate visits the device ran (every row of a vmapped sweep loop visits
-all m candidates for as many sweeps as the loop made) and the matroid kind.
+trips of its sweep and block loops (the most any row made), for the
+``solve.run`` span, which also records the candidate visits the sweeps
+cover (every row of a vmapped sweep loop covers all m candidates for as
+many sweeps as the loop made) and the matroid kind.
 
 Matroid feasibility inside the greedy/swap loops comes in two flavours,
 chosen statically per matroid kind:
@@ -27,7 +35,9 @@ chosen statically per matroid kind:
   ``solvers.matching``: "can candidate v extend (or swap into) the current
   selection" is answered exactly, by the same alternating-path truth the
   host oracle computes, so accept/reject decisions are identical to
-  ``local_search_sum`` under a ``TransversalMatroid``.
+  ``local_search_sum`` under a ``TransversalMatroid``. The swap test of
+  every candidate reads one ``swap_table`` per selection, rebuilt after
+  each swap, instead of walking paths per candidate.
 
 Everything is masked to static shapes: queries are padded to the batch's
 ``kmax`` (bucketed to the next power of two so novel max-k values don't
@@ -53,7 +63,7 @@ from .base import (
     SolverEngine,
     selection_value,
 )
-from .matching import augment, cats_onehot, feasible_all, swap_feasible
+from .matching import augment, cats_onehot, feasible_all, swap_table
 
 
 def _dot(a, b):
@@ -141,14 +151,129 @@ def to_device(*host) -> list:
 
 def vmap_rows(row_solver, D, matroid, *rows):
     """``row_solver`` vmapped over the batch's rows, with D and the shared
-    matroid array unmapped. Returns (sel, nsel, div) per row, then two
-    int32 scalars: ``swaps``, the swaps all rows made, and ``sweeps``, the
-    trips of the batched sweep loop (the most any row made)."""
+    matroid array unmapped. Returns (sel, nsel, div) per row, then three
+    int32 scalars: ``swaps``, the swaps all rows made, ``sweeps``, the
+    trips of the batched sweep loop, and ``trips``, those of its block
+    loop (each the most any row made)."""
     in_axes = (None, None) + (0,) * len(rows)
-    sel, nsel, div, swaps, sweeps = jax.vmap(row_solver, in_axes=in_axes)(
-        D, matroid, *rows
+    sel, nsel, div, swaps, sweeps, trips = jax.vmap(
+        row_solver, in_axes=in_axes)(D, matroid, *rows)
+    return sel, nsel, div, jnp.sum(swaps), jnp.max(sweeps), jnp.max(trips)
+
+
+# --------------------------------------------------------------------------
+# the local search, shared by both feasibility families
+# --------------------------------------------------------------------------
+
+
+def _block_size(m: int, kmax: int) -> int:
+    """Candidates one trip of the local search's block loop tests: the
+    largest power of two T <= 256 with T * kmax <= 32768, and no more
+    than m. A trip costs a fixed loop overhead plus work in T * kmax
+    (the (kmax, T) swap values and tests), and a swap at lane c wastes
+    the lanes after it, since the next trip starts at the candidate
+    after it. On a TPU v5e the fixed cost rules: at kmax 128 a trip of
+    256 candidates cost about what one of 64 did, so T is as large as
+    keeps the (kmax, T) values at 128 KiB."""
+    t = 1
+    while 2 * t <= 256 and 2 * t * kmax <= 32768:
+        t *= 2
+    return min(t, m)
+
+
+def _local_search(D, allow, k, gamma, sel, selmask, nsel, mat, swap_ok,
+                  swap_mat, *, kmax, max_sweeps):
+    """First-improvement local search from a greedy seed, one query.
+
+    Each sweep scans the candidates in blocks: a trip takes the T
+    candidates from ``pos`` (the window clamped to end at m, lanes below
+    ``pos`` masked), computes every (u, v) swap value with the host's
+    arithmetic, and takes the first v with an improving, feasible u,
+    then the first such u in X order: the host's (v, u) order. On a hit
+    it swaps and the next trip starts at v + 1; otherwise at the end of
+    the window. The loop state changes only on a swap, so this makes the
+    host's decisions, bit for bit, in ~m/T + swaps trips a sweep.
+
+    The matroid enters as ``mat``, its loop state, with
+    ``swap_ok(mat, sel, w0, T)`` -> bool (kmax, T), whether slot j may
+    leave for candidate w0 + t, and ``swap_mat(mat, sel2, uold, v)``,
+    the state after the swap. Returns (sel, nsel, div, swaps, sweeps,
+    trips), with the trips of all of this row's sweeps.
+    """
+    m = D.shape[0]
+    T = _block_size(m, kmax)
+    slots = jnp.arange(kmax, dtype=jnp.int32)
+    lanes = jnp.arange(T, dtype=jnp.int32)
+
+    def d_rows(w0, u):
+        # D[u, w0:w0+T] for every slot: u is the row index, as the host reads
+        return jax.vmap(
+            lambda r: jax.lax.dynamic_slice(D, (r, w0), (1, T))[0])(u)
+
+    def block_cond(carry):
+        return carry[0] < m
+
+    def block_body(carry):
+        pos, trips, st = carry
+        sel, selmask, mat, rowX, div, _swaps, _imp = st
+        w0 = jnp.minimum(pos, m - T)
+        u = jnp.maximum(sel, 0)  # (kmax,) slot -> local id (garbage past k)
+        # div(X - u + v) = div - row[u] + dv - d(u, v)   (host's identity)
+        new_div = ((div - rowX[u])[:, None]
+                   + jax.lax.dynamic_slice(rowX, (w0,), (T,))[None, :]
+                   - d_rows(w0, u))
+        improving = (
+            (slots < nsel)[:, None]
+            & (new_div > div * (1.0 + gamma))
+            & (new_div > div)
+            & swap_ok(mat, sel, w0, T)
+        )
+        hit = (
+            (w0 + lanes >= pos)
+            & jax.lax.dynamic_slice(allow, (w0,), (T,))
+            & ~jax.lax.dynamic_slice(selmask, (w0,), (T,))
+            & jnp.any(improving, axis=0)
+        )
+        c = jnp.argmax(hit)  # first candidate of the block that swaps
+        v = w0 + c
+        ui = jnp.argmax(improving[:, c])  # first improving u in X order
+        found = jnp.any(hit)
+
+        def do_swap(st):
+            sel, selmask, mat, _rowX, _div, swaps, _imp = st
+            uold = sel[ui]
+            # host order: X = [w for w in X if w != u] + [v]
+            src = jnp.where(slots >= ui, jnp.minimum(slots + 1, kmax - 1),
+                            slots)
+            sel2 = sel[src].at[nsel - 1].set(v)
+            selmask2 = selmask.at[uold].set(False).at[v].set(True)
+            rowX2 = _dot(D, selmask2.astype(jnp.float32))
+            return (sel2, selmask2, swap_mat(mat, sel2, uold, v), rowX2,
+                    new_div[ui, c], swaps + 1, True)
+
+        st = _cond_once(found, do_swap, st)
+        return jnp.where(found, v + 1, w0 + T), trips + 1, st
+
+    def sweep_cond(carry):
+        st, sweeps, _trips = carry
+        return st[-1] & (sweeps < max_sweeps)
+
+    def sweep_body(carry):
+        st, sweeps, trips = carry
+        st = (*st[:-1], False)
+        _pos, trips, st = jax.lax.while_loop(
+            block_cond, block_body, (jnp.int32(0), trips, st))
+        return st, sweeps + 1, trips
+
+    selm_f = selmask.astype(jnp.float32)
+    rowX0 = _dot(D, selm_f)
+    div0 = 0.5 * _dot(selm_f, rowX0)
+    ls0 = ((sel, selmask, mat, rowX0, div0, jnp.int32(0), nsel == k),
+           jnp.int32(0), jnp.int32(0))
+    (sel, _selmask, _mat, _rowX, div, swaps, _imp), sweeps, trips = (
+        jax.lax.while_loop(sweep_cond, sweep_body, ls0)
     )
-    return sel, nsel, div, jnp.sum(swaps), jnp.max(sweeps)
+    return sel, nsel, div, swaps, sweeps, trips
 
 
 # --------------------------------------------------------------------------
@@ -194,58 +319,19 @@ def _greedy_seed(D, cats, caps, allow, k, kmax):
 
 def _solve_sum_one(D, cats, caps, allow, k, gamma, *, kmax, max_sweeps):
     """Single-query greedy + first-improvement local search over cached D."""
-    m = D.shape[0]
     sel, selmask, counts, nsel = _greedy_seed(D, cats, caps, allow, k, kmax)
-    selm_f = selmask.astype(jnp.float32)
-    div0 = 0.5 * _dot(selm_f, _dot(D, selm_f))
-    slots = jnp.arange(kmax, dtype=jnp.int32)
 
-    def v_body(v, st):
-        sel, selmask, counts, rowX, div, swaps, improved = st
-        u = jnp.maximum(sel, 0)  # (kmax,) slot -> local id (garbage past k)
-        # div(X - u + v) = div - row[u] + dv - d(u, v)   (host's identity)
-        new_div = div - rowX[u] + rowX[v] - D[u, v]
-        cat_v = cats[v]
-        ok_cap = counts[cat_v] - (cats[u] == cat_v) + 1 <= caps[cat_v]
-        improving = (
-            (slots < nsel)
-            & (new_div > div * (1.0 + gamma))
-            & (new_div > div)
-            & ok_cap
-        )
-        any_imp = allow[v] & ~selmask[v] & jnp.any(improving)
-        ui = jnp.argmax(improving)  # first improving u in X order
+    def swap_ok(counts, sel, w0, T):
+        u = jnp.maximum(sel, 0)
+        cat_v = jax.lax.dynamic_slice(cats, (w0,), (T,))
+        return (counts[cat_v][None, :] - (cats[u][:, None] == cat_v[None, :])
+                + 1 <= caps[cat_v][None, :])
 
-        def do_swap(st):
-            sel, selmask, counts, rowX, div, swaps, improved = st
-            uold = sel[ui]
-            # host order: X = [w for w in X if w != u] + [v]
-            src = jnp.where(slots >= ui, jnp.minimum(slots + 1, kmax - 1), slots)
-            sel2 = sel[src].at[nsel - 1].set(v)
-            selmask2 = selmask.at[uold].set(False).at[v].set(True)
-            counts2 = counts.at[cats[uold]].add(-1).at[cat_v].add(1)
-            rowX2 = _dot(D, selmask2.astype(jnp.float32))
-            return sel2, selmask2, counts2, rowX2, new_div[ui], swaps + 1, True
+    def swap_mat(counts, sel2, uold, v):
+        return counts.at[cats[uold]].add(-1).at[cats[v]].add(1)
 
-        return _cond_once(any_imp, do_swap, st)
-
-    def sweep_cond(carry):
-        st, sweeps = carry
-        return st[-1] & (sweeps < max_sweeps)
-
-    def sweep_body(carry):
-        st, sweeps = carry
-        st = (*st[:-1], False)
-        st = jax.lax.fori_loop(0, m, v_body, st)
-        return st, sweeps + 1
-
-    rowX0 = _dot(D, selm_f)
-    ls0 = ((sel, selmask, counts, rowX0, div0, jnp.int32(0), nsel == k),
-           jnp.int32(0))
-    (sel, selmask, counts, _rowX, div, swaps, _imp), sweeps = (
-        jax.lax.while_loop(sweep_cond, sweep_body, ls0)
-    )
-    return sel, nsel, div, swaps, sweeps
+    return _local_search(D, allow, k, gamma, sel, selmask, nsel, counts,
+                         swap_ok, swap_mat, kmax=kmax, max_sweeps=max_sweeps)
 
 
 @functools.partial(jax.jit, static_argnames=("kmax", "max_sweeps"))
@@ -262,7 +348,7 @@ def solve_sum_batch(
 ):
     """Batch of sum-DMMC queries on one matrix (uniform/partition).
     Returns (sel (B, kmax) local ids -1-padded, nsel (B,), div (B,),
-    swaps, sweeps) as ``vmap_rows`` counts them."""
+    swaps, sweeps, trips) as ``vmap_rows`` counts them."""
     f = functools.partial(_solve_sum_one, kmax=kmax, max_sweeps=max_sweeps)
     with jax.named_scope("solver/jit_sum"):
         return vmap_rows(f, D, cats, caps, allow, ks, gammas)
@@ -313,58 +399,26 @@ def _solve_sum_one_tv(D, oh, allow, k, gamma, *, kmax, max_sweeps):
     """Single transversal sum query: greedy + first-improvement local
     search, swap feasibility via masked augmenting paths. Mirrors
     ``local_search_sum`` under a ``TransversalMatroid`` decision for
-    decision (feasibility truth is matching-independent)."""
-    m = D.shape[0]
+    decision (feasibility truth is matching-independent). The loop state
+    carries the matching and its ``swap_table``, rebuilt on each swap."""
     sel, selmask, ms_pt, nsel = _greedy_seed_tv(D, oh, allow, k, kmax)
-    selm_f = selmask.astype(jnp.float32)
-    div0 = 0.5 * _dot(selm_f, _dot(D, selm_f))
-    slots = jnp.arange(kmax, dtype=jnp.int32)
 
-    def v_body(v, st):
-        sel, selmask, ms_pt, rowX, div, swaps, improved = st
-        u = jnp.maximum(sel, 0)
-        new_div = div - rowX[u] + rowX[v] - D[u, v]
-        ok_swap = swap_feasible(oh, ms_pt, sel, v)  # (kmax,) exact
-        improving = (
-            (slots < nsel)
-            & (new_div > div * (1.0 + gamma))
-            & (new_div > div)
-            & ok_swap
-        )
-        any_imp = allow[v] & ~selmask[v] & jnp.any(improving)
-        ui = jnp.argmax(improving)
+    def swap_ok(mat, sel, w0, T):
+        _ms, F = mat
+        oh_v = jax.lax.dynamic_slice(oh, (w0, 0), (T, oh.shape[1]))
+        # (kmax, h) . (h, T) over 0/1 entries: exact at any precision
+        return jnp.dot(F.astype(jnp.float32), oh_v.T.astype(jnp.float32)) > 0
 
-        def do_swap(st):
-            sel, selmask, ms_pt, rowX, div, swaps, improved = st
-            uold = sel[ui]
-            src = jnp.where(slots >= ui, jnp.minimum(slots + 1, kmax - 1), slots)
-            sel2 = sel[src].at[nsel - 1].set(v)
-            selmask2 = selmask.at[uold].set(False).at[v].set(True)
-            # rebuild the matching: free u's category, re-insert v
-            ms2 = jnp.where(ms_pt == uold, jnp.int32(-1), ms_pt)
-            ms2 = augment(oh, ms2, v, kmax)
-            rowX2 = _dot(D, selmask2.astype(jnp.float32))
-            return sel2, selmask2, ms2, rowX2, new_div[ui], swaps + 1, True
+    def swap_mat(mat, sel2, uold, v):
+        ms_pt, _F = mat
+        # rebuild the matching: free u's category, re-insert v
+        ms2 = jnp.where(ms_pt == uold, jnp.int32(-1), ms_pt)
+        ms2 = augment(oh, ms2, v, kmax)
+        return ms2, swap_table(oh, ms2, sel2)
 
-        return _cond_once(any_imp, do_swap, st)
-
-    def sweep_cond(carry):
-        st, sweeps = carry
-        return st[-1] & (sweeps < max_sweeps)
-
-    def sweep_body(carry):
-        st, sweeps = carry
-        st = (*st[:-1], False)
-        st = jax.lax.fori_loop(0, m, v_body, st)
-        return st, sweeps + 1
-
-    rowX0 = _dot(D, selm_f)
-    ls0 = ((sel, selmask, ms_pt, rowX0, div0, jnp.int32(0), nsel == k),
-           jnp.int32(0))
-    (sel, _selmask, _ms, _rowX, div, swaps, _imp), sweeps = (
-        jax.lax.while_loop(sweep_cond, sweep_body, ls0)
-    )
-    return sel, nsel, div, swaps, sweeps
+    mat = (ms_pt, swap_table(oh, ms_pt, sel))
+    return _local_search(D, allow, k, gamma, sel, selmask, nsel, mat,
+                         swap_ok, swap_mat, kmax=kmax, max_sweeps=max_sweeps)
 
 
 @functools.partial(jax.jit, static_argnames=("kmax", "max_sweeps"))
@@ -379,8 +433,8 @@ def solve_sum_batch_transversal(
     max_sweeps: int = 64,
 ):
     """Batch of sum-DMMC queries under ONE transversal matroid.
-    Returns (sel (B, kmax) -1-padded, nsel (B,), div (B,), swaps, sweeps)
-    as ``vmap_rows`` counts them."""
+    Returns (sel (B, kmax) -1-padded, nsel (B,), div (B,), swaps, sweeps,
+    trips) as ``vmap_rows`` counts them."""
     f = functools.partial(_solve_sum_one_tv, kmax=kmax, max_sweeps=max_sweeps)
     with jax.named_scope("solver/jit_sum_tv"):
         return vmap_rows(f, D, oh, allow, ks, gammas)
@@ -436,11 +490,12 @@ class JitSumBatchEngine(SolverEngine):
             with obs.compile_region(
                 f"solve[{key} B={Bb} kmax={kmax} m={ctx.size}]"
             ):
-                sel, nsel, _div, swaps, sweeps = fn(*args, kmax=kmax)
-            sel, nsel, swaps, sweeps = jax.device_get(
-                (sel, nsel, swaps, sweeps)
+                sel, nsel, _div, swaps, sweeps, trips = fn(*args, kmax=kmax)
+            sel, nsel, swaps, sweeps, trips = jax.device_get(
+                (sel, nsel, swaps, sweeps, trips)
             )
             run.update(swaps=int(swaps), sweeps=int(sweeps),
+                       trips=int(trips),
                        visits=Bb * ctx.size * int(sweeps),
                        matroid=ctx.spec.kind)
         out = []

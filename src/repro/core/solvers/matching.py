@@ -136,6 +136,16 @@ def feasible_all(
     return jnp.any(reach & free, axis=1)
 
 
+def _swap_variants(oh, ms_pt, sel):
+    """(Ms float32[kmax, h, h], free bool[kmax, h]): the reachability
+    matrix and the free categories of variant j, the matching with
+    sel[j]'s category freed."""
+    u = jnp.maximum(sel, 0)
+    ms_var = jnp.where(ms_pt[None, :] == u[:, None], -1, ms_pt[None, :])
+    Ms = jax.vmap(reach_matrix, in_axes=(None, 0))(oh, ms_var)
+    return Ms.astype(jnp.float32), ms_var < 0
+
+
 def swap_feasible(
     oh: jnp.ndarray,  # (m, h) bool
     ms_pt: jnp.ndarray,  # (h,) int32
@@ -146,15 +156,12 @@ def swap_feasible(
 
     Variant j frees sel[j]'s matched category, then asks for an augmenting
     path from v. Rows for invalid slots (sel[j] < 0) are garbage; callers
-    mask them with ``slots < nsel``.
+    mask them with ``slots < nsel``. The reference that ``swap_table`` is
+    tested against.
     """
     kmax = sel.shape[0]
     h = ms_pt.shape[0]
-    u = jnp.maximum(sel, 0)
-    ms_var = jnp.where(ms_pt[None, :] == u[:, None], -1, ms_pt[None, :])
-    Ms = jax.vmap(reach_matrix, in_axes=(None, 0))(oh, ms_var)
-    Ms = Ms.astype(jnp.float32)  # (kmax, h, h)
-    free = ms_var < 0  # (kmax, h)
+    Ms, free = _swap_variants(oh, ms_pt, sel)
     reach0 = jnp.broadcast_to(oh[v], (kmax, h))
 
     def step(_, reach):
@@ -163,6 +170,39 @@ def swap_feasible(
 
     reach = jax.lax.fori_loop(0, kmax, step, reach0)
     return jnp.any(reach & free, axis=1)
+
+
+def swap_table(
+    oh: jnp.ndarray,  # (m, h) bool
+    ms_pt: jnp.ndarray,  # (h,) int32
+    sel: jnp.ndarray,  # (kmax,) int32 selected point ids (-1 padded)
+) -> jnp.ndarray:
+    """bool[kmax, h]: F[j, c] says whether, in variant j (sel[j]'s category
+    freed), an alternating path from category c reaches a free category.
+
+    ``swap_feasible`` for every candidate at once: X - sel[j] + v is
+    independent iff ``any(oh[v] & F[j])``. Built backward from the free
+    categories with the same reachability products; a path of at most
+    kmax steps is the bound ``swap_feasible`` walks forward, and the walk
+    stops early at its fixpoint, where further steps change nothing.
+    F depends only on the matching and the selection, so a local search
+    builds it once per swap and reads every candidate's test from it.
+    """
+    kmax = sel.shape[0]
+    Ms, free = _swap_variants(oh, ms_pt, sel)
+
+    def cond(c):
+        _F, changed, i = c
+        return changed & (i < kmax)
+
+    def step(c):
+        F, _changed, i = c
+        nxt = F | (jnp.einsum("jcd,jd->jc", Ms, F.astype(jnp.float32)) > 0)
+        return nxt, jnp.any(nxt != F), i + 1
+
+    F, _, _ = jax.lax.while_loop(cond, step, (free, jnp.bool_(True),
+                                              jnp.int32(0)))
+    return F
 
 
 def augment(

@@ -85,7 +85,8 @@ def solve_sum_batch_stacked(
 ):
     """T tenant lanes of Bt sum-DMMC rows each, ONE dispatch.  Returns
     (sel (T, Bt, kmax) -1-padded, nsel (T, Bt), div (T, Bt), swaps,
-    sweeps): the swaps of all rows, and the sweep-loop trips of all lanes.
+    sweeps, trips): the swaps of all rows, and the sweep-loop and
+    block-loop trips of all lanes.
 
     ``lax.scan`` (not an outer vmap) on purpose: inside each scan step
     the lane's D is a concrete (m, m) operand, so the inner vmapped
@@ -99,10 +100,10 @@ def solve_sum_batch_stacked(
         return carry, vmap_rows(f, *xs)
 
     with jax.named_scope("solver/jit_sum_stacked"):
-        _, (sel, nsel, div, swaps, sweeps) = jax.lax.scan(
+        _, (sel, nsel, div, swaps, sweeps, trips) = jax.lax.scan(
             lane, jnp.int32(0), (Ds, cats_s, caps, allow, ks, gammas)
         )
-    return sel, nsel, div, jnp.sum(swaps), jnp.sum(sweeps)
+    return sel, nsel, div, jnp.sum(swaps), jnp.sum(sweeps), jnp.sum(trips)
 
 
 def solve_stacked(lanes: Sequence[Lane]) -> list[list[EngineSolution]]:
@@ -170,12 +171,13 @@ def solve_stacked(lanes: Sequence[Lane]) -> list[list[EngineSolution]]:
         with obs.compile_region(
             f"solve[jit_sum_stacked T={Tb} B={Bt} kmax={kmax} m={m}]"
         ):
-            sel, nsel, _div, swaps, sweeps = solve_sum_batch_stacked(
+            sel, nsel, _div, swaps, sweeps, trips = solve_sum_batch_stacked(
                 *args, kmax=kmax
             )
-        sel, nsel, swaps, sweeps = jax.device_get((sel, nsel, swaps, sweeps))
+        sel, nsel, swaps, sweeps, trips = jax.device_get(
+            (sel, nsel, swaps, sweeps, trips))
         # each lane's loop runs its Bt rows over all m candidates per sweep
-        run.update(swaps=int(swaps), sweeps=int(sweeps),
+        run.update(swaps=int(swaps), sweeps=int(sweeps), trips=int(trips),
                    visits=Bt * m * int(sweeps),
                    matroid=",".join(sorted({ctx.spec.kind
                                             for ctx, _specs in lanes})))

@@ -429,9 +429,11 @@ def test_every_jit_launch_path_records_its_phases(rng, launch):
     ph = _phases_under_solve(buf.drain())
     assert ph["solve.h2d"].args["bytes"] == want
     run = ph["solve.run"].args
-    assert set(run) == {"swaps", "sweeps", "visits", "matroid"}
+    assert set(run) == {"swaps", "sweeps", "trips", "visits", "matroid"}
     # every row of the launch visits all 24 candidates in each sweep
     assert run["visits"] > 0 and run["visits"] % (24 * run["sweeps"]) == 0
+    # the block loop makes at least one trip a sweep, at most one a visit
+    assert run["sweeps"] <= run["trips"] <= run["visits"]
     assert run["matroid"] == ("transversal" if launch is _launch_transversal
                               else "partition")
 
